@@ -255,9 +255,9 @@ def _delta_child() -> None:
     print("DELTAROWS:" + json.dumps(rows))
 
 
-def sweep_delta_signatures() -> Optional[List[Dict]]:
+def sweep_delta_signatures() -> List[Dict]:
     """Run the delta-signature sweep in a fresh 8-host-device process
-    (jax pins the device count at first init).  None when it fails."""
+    (jax pins the device count at first init).  Raises when it fails."""
     n_dev = int(np.prod(MESH_SHAPE))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
@@ -266,18 +266,14 @@ def sweep_delta_signatures() -> Optional[List[Dict]]:
                                        "src"))
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
-    try:
-        out = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--delta-child"],
-            env=env, capture_output=True, text=True, timeout=900)
-    except subprocess.TimeoutExpired:
-        return None
+    out = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--delta-child"],
+        env=env, capture_output=True, text=True, timeout=900)
     lines = [l for l in out.stdout.splitlines()
              if l.startswith("DELTAROWS:")]
     if out.returncode != 0 or not lines:
-        print(f"[bench_autotune] delta-signature sweep failed:\n"
-              f"{out.stderr[-2000:]}")
-        return None
+        raise RuntimeError(f"delta-signature sweep child failed "
+                           f"(rc={out.returncode}):\n{out.stderr[-2000:]}")
     rows = json.loads(lines[0][len("DELTAROWS:"):])
     for r in rows:
         print(f"  max_delta_signatures={r['cfg']['max_delta_signatures']:>3}"
@@ -324,14 +320,12 @@ def tune(out_dir: Optional[str] = None, quick: bool = False,
         if not (quick or skip_mesh):
             print("[bench_autotune] delta-signature sweep (mesh child)")
             delta_rows = sweep_delta_signatures()
-            if delta_rows:
-                d_win = pick_winner(
-                    delta_rows,
-                    {"max_delta_signatures":
-                     fd.DEFAULT_MAX_DELTA_SIGNATURES})
-                delta = int(d_win["cfg"]["max_delta_signatures"])
-                swept["delta_signatures"] = {"rows": delta_rows,
-                                             "winner": d_win["cfg"]}
+            d_win = pick_winner(
+                delta_rows,
+                {"max_delta_signatures": fd.DEFAULT_MAX_DELTA_SIGNATURES})
+            delta = int(d_win["cfg"]["max_delta_signatures"])
+            swept["delta_signatures"] = {"rows": delta_rows,
+                                         "winner": d_win["cfg"]}
         profile = TunedProfile(
             backend=backend,
             buckets=tuple(flush_win["cfg"]["buckets"]),
@@ -420,8 +414,15 @@ def main() -> None:
         return
     if args.check:
         sys.exit(check())
+    skip_mesh = args.skip_mesh
+    if not skip_mesh and jax.default_backend() != "cpu":
+        # a JAX child would contend with this process for the chip
+        print(f"[bench_autotune] backend {jax.default_backend()}: the "
+              "forced-host-device delta-signature sweep is a CPU "
+              "rehearsal and was skipped")
+        skip_mesh = True
     prof = tune(out_dir=args.out_dir, quick=args.quick,
-                skip_ring=args.skip_ring, skip_mesh=args.skip_mesh)
+                skip_ring=args.skip_ring, skip_mesh=skip_mesh)
     print(f"[bench_autotune] winner: buckets={list(prof.buckets)} "
           f"overlap={prof.overlap} ring={prof.ring_capacity} "
           f"max_delta_signatures={prof.max_delta_signatures} "

@@ -640,7 +640,7 @@ def _serve_child() -> None:
                                      "summary": summary}))
 
 
-def _run_serve_section(skip_mesh: bool) -> Optional[Dict]:
+def _run_serve_section(skip_mesh: bool) -> Dict:
     rows = [_bench_serve_path(*p) for p in SERVE_PATHS]
     section = {
         "arch": f"{SERVE_ARCH} (reduced)",
@@ -655,14 +655,7 @@ def _run_serve_section(skip_mesh: bool) -> Optional[Dict]:
     }
     if skip_mesh:
         return section
-    out = _run_child("--serve-mesh-child")
-    lines = [] if out is None or out.returncode != 0 else [
-        l for l in out.stdout.splitlines() if l.startswith("SERVEROWS:")]
-    if not lines:
-        err = "timeout" if out is None else out.stderr[-2000:]
-        print(f"[bench_dispatch] serve mesh leg failed:\n{err}")
-        return section
-    payload = json.loads(lines[0][len("SERVEROWS:"):])
+    payload = _child_payload("--serve-mesh-child", "SERVEROWS:")
     section["mesh"] = {
         "devices": int(np.prod(MESH_SHAPE)),
         "mesh_shape": list(MESH_SHAPE),
@@ -795,18 +788,11 @@ def _run_traffic_section(skip_mesh: bool) -> Dict:
     }
     if skip_mesh:
         return section
-    out = _run_child("--traffic-mesh-child")
-    lines = [] if out is None or out.returncode != 0 else [
-        l for l in out.stdout.splitlines()
-        if l.startswith("TRAFFICPARITY:")]
-    if not lines:
-        err = "timeout" if out is None else out.stderr[-2000:]
-        print(f"[bench_dispatch] traffic mesh leg failed:\n{err}")
-        return section
     section["mesh"] = {
         "devices": int(np.prod(MESH_SHAPE)),
         "mesh_shape": list(MESH_SHAPE),
-        "preempt_parity": json.loads(lines[0][len("TRAFFICPARITY:"):]),
+        "preempt_parity": _child_payload("--traffic-mesh-child",
+                                         "TRAFFICPARITY:"),
     }
     return section
 
@@ -918,8 +904,23 @@ def _mesh_child() -> None:
     print("MESHROWS:" + json.dumps(rows))
 
 
-def _run_child(flag: str):
-    """Run this file in a fresh interpreter with 8 forced host devices."""
+def _host_rehearsals_skipped() -> bool:
+    """The forced-host-device sections are CPU rehearsals of the mesh
+    paths.  Off the CPU they are skipped, with one line saying so: a JAX
+    child would contend with this process for its accelerator."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return False
+    print(f"[bench_dispatch] backend {backend}: the forced-host-device "
+          "mesh sections are CPU rehearsals and were skipped")
+    return True
+
+
+def _child_payload(flag: str, prefix: str):
+    """Run this file in a fresh interpreter with 8 forced host devices
+    and return the JSON payload of its ``prefix`` line.  A child that
+    times out, fails or prints no payload raises: a failed section never
+    turns into a ``null`` in the results."""
     n_dev = int(np.prod(MESH_SHAPE))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
@@ -928,23 +929,18 @@ def _run_child(flag: str):
                                        "src"))
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
-    try:
-        return subprocess.run(
-            [sys.executable, os.path.abspath(__file__), flag],
-            env=env, capture_output=True, text=True, timeout=1200)
-    except subprocess.TimeoutExpired:
-        return None
+    out = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), flag],
+        env=env, capture_output=True, text=True, timeout=1200)
+    lines = [l for l in out.stdout.splitlines() if l.startswith(prefix)]
+    if out.returncode != 0 or not lines:
+        raise RuntimeError(f"bench_dispatch child {flag} failed "
+                           f"(rc={out.returncode}):\n{out.stderr[-2000:]}")
+    return json.loads(lines[0][len(prefix):])
 
 
-def _run_mesh_section() -> Optional[Dict]:
-    out = _run_child("--mesh-child")
-    lines = [] if out is None else [
-        l for l in out.stdout.splitlines() if l.startswith("MESHROWS:")]
-    if out is None or out.returncode != 0 or not lines:
-        err = "timeout" if out is None else out.stderr[-2000:]
-        print(f"[bench_dispatch] mesh section failed:\n{err}")
-        return None
-    rows = json.loads(lines[0][len("MESHROWS:"):])
+def _run_mesh_section() -> Dict:
+    rows = _child_payload("--mesh-child", "MESHROWS:")
     f = [r for r in rows if r["path"] == "fused"]
     s = [r for r in rows if r["path"] == "seed"]
     return {
@@ -1179,7 +1175,8 @@ def main() -> None:
         sys.exit(serve_smoke())
     if args.traffic_smoke:
         sys.exit(traffic_smoke())
-    result = run(skip_mesh=args.skip_mesh, skip_serve=args.skip_serve)
+    skip_mesh = args.skip_mesh or _host_rehearsals_skipped()
+    result = run(skip_mesh=skip_mesh, skip_serve=args.skip_serve)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=2)
     print(f"{'batch':>6} {'path':>6} {'launches':>9} {'table':>6} "

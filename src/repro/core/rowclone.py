@@ -79,8 +79,9 @@ from repro.core.poolspec import BlockRef, PoolGroup
 from repro.core.sanitizer import DrainSanitizer, sanitize_enabled
 from repro.core.stream import CommandStream
 from repro.kernels import ops as kops
-from repro.kernels.fused_dispatch import (DrainInfo, _bitcast_uint,
-                                          check_drain, notify_launch)
+# module import, not names: the kernel module imports core.opcodes, so
+# importing it first must not need its names while core initializes
+from repro.kernels import fused_dispatch as kfd
 from repro.models.paged import pool_shard_axes, pool_shard_count
 from repro.obs import metrics as obs_metrics
 from repro.obs.autotune import load_profile
@@ -597,7 +598,7 @@ class RowCloneEngine:
             for ci, lo in enumerate(range(0, len(spaced), top)):
                 chunk = spaced[lo:lo + top]
                 try:
-                    check_drain(DrainInfo(
+                    kfd.check_drain(kfd.DrainInfo(
                         flush=idx, chunk=ci,
                         n_commands=sum(1 for r in chunk if r[0] >= 0),
                         n_pools=len(self.pools), engine=self))
@@ -1460,7 +1461,7 @@ class RowCloneEngine:
                     self.pools[name] = kops.fpm_copy(
                         self.pools[name], ids,
                         use_pallas=self._legacy_use_pallas())
-                notify_launch(self.max_requests, 1, "legacy_fpm")
+                kfd.notify_launch(self.max_requests, 1, "legacy_fpm")
                 launches += 1
         return launches
 
@@ -1475,7 +1476,7 @@ class RowCloneEngine:
             ids = jnp.asarray(self._pad(chunk))
             for name in self.primary_names:
                 self.pools[name] = fn(self.pools[name], ids)
-                notify_launch(self.max_requests, 1, "legacy_psm")
+                kfd.notify_launch(self.max_requests, 1, "legacy_psm")
                 launches += 1
         return launches
 
@@ -1490,7 +1491,7 @@ class RowCloneEngine:
                 else:
                     self.pools[name] = kops.baseline_copy(self.pools[name],
                                                           ids)
-                notify_launch(self.max_requests, 1, "legacy_baseline")
+                kfd.notify_launch(self.max_requests, 1, "legacy_baseline")
                 launches += 1
         return launches
 
@@ -1510,7 +1511,7 @@ class RowCloneEngine:
                     self.pools[name] = kops.meminit_zero(
                         pool, zero_block, idv,
                         use_pallas=self._legacy_use_pallas())
-                notify_launch(self.max_requests, 1, "legacy_zero")
+                kfd.notify_launch(self.max_requests, 1, "legacy_zero")
                 launches += 1
         return launches
 
@@ -1545,7 +1546,7 @@ class RowCloneEngine:
                     self.pools[names[pd]] = kops.fpm_copy_cross(
                         self.pools[names[pd]], self.pools[names[ps]], ids,
                         use_pallas=self._legacy_use_pallas())
-                notify_launch(self.max_requests, 1, "legacy_cross")
+                kfd.notify_launch(self.max_requests, 1, "legacy_cross")
                 launches += 1
             i = j
         return launches
@@ -1583,7 +1584,7 @@ class RowCloneEngine:
                     self.pools[names[pd]], self.pools[names[pa]],
                     self.pools[names[pb]], jnp.asarray(arr), op=int(op),
                     block_axis=self.block_axis)
-                notify_launch(self.max_requests, 1, "legacy_bitwise")
+                kfd.notify_launch(self.max_requests, 1, "legacy_bitwise")
                 launches += 1
             i = j
         return launches
@@ -1640,8 +1641,8 @@ def _bitwise_jit(dst_pool, a_pool, b_pool, ids, *, op, block_axis):
         cl = jnp.clip(idx, 0, pool.shape[ba] - 1)
         return pool[cl] if ba == 0 else pool[:, cl]
 
-    au = _bitcast_uint(gather(a_pool, ids[:, 0]))
-    bu = _bitcast_uint(gather(b_pool, ids[:, 1]))
+    au = kfd._bitcast_uint(gather(a_pool, ids[:, 0]))
+    bu = kfd._bitcast_uint(gather(b_pool, ids[:, 1]))
     if op == OP_AND:
         ru = au & bu
     elif op == OP_OR:

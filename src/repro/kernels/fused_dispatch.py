@@ -74,7 +74,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 # the opcode table is DECLARED once, in the core/opcodes.py registry; the
 # kernel (like the CommandQueue and the jnp reference) derives its switch
 # sets from it.  The names are re-exported here for the long-standing
@@ -583,11 +582,12 @@ def _sharded_runner(mesh, pool_axes: Tuple[str, ...], deltas: Tuple[int, ...],
                         ((dst_pool < 0) | (dst_pool == pd)) if primary[pd]
                         else (dst_pool == pd))
                     if phase == 0:
+                        # select raw bits, not floats, as kernels/ref.py
+                        # does
                         pu = _bitcast_uint(picked)
-                        inv = jax.lax.bitcast_convert_type(~pu,
-                                                           picked.dtype)
-                        data = jnp.where(expand(comb == OP_NOT, picked),
-                                         inv, picked)
+                        data = jax.lax.bitcast_convert_type(
+                            jnp.where(expand(comb == OP_NOT, pu), ~pu, pu),
+                            picked.dtype)
                     else:
                         cur = _gather_rows(
                             slabs[pd], jnp.where(valid, dst_row, 0),
@@ -601,7 +601,7 @@ def _sharded_runner(mesh, pool_axes: Tuple[str, ...], deltas: Tuple[int, ...],
                                               valid, block_axis)
         return tuple(slabs)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         # P() replicates the zero rows; per-pool specs shard or replicate
         # each pool leaf according to its PoolSpec.sharding hint

@@ -16,9 +16,7 @@ import jax.numpy as jnp
 from repro.kernels import ref as kref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fpm_copy import fpm_copy_cross_pallas, fpm_copy_pallas
-from repro.kernels.fused_dispatch import (fused_dispatch_pallas,
-                                          notify_launch,
-                                          sharded_fused_dispatch)
+from repro.kernels import fused_dispatch as kfd
 from repro.kernels.paged_attention import paged_attention_slab_pallas
 from repro.kernels.ssd_chunk import ssd_intra_chunk_pallas
 from repro.kernels.zero_init import zero_init_pallas
@@ -87,16 +85,15 @@ def fused_dispatch(pools, zero_blocks, cmds, *, block_axis: int = 0,
     overlapped vs serial DMA drain (a tuned-profile knob; the jnp
     reference has no DMA pipeline, so it ignores it).
     """
-    from repro.kernels.fused_dispatch import _as_primary
-    primary = _as_primary(primary, len(pools))
+    primary = kfd._as_primary(primary, len(pools))
     if _resolve_use_pallas(use_pallas):
-        return fused_dispatch_pallas(pools, zero_blocks, cmds,
-                                     block_axis=block_axis,
-                                     interpret=_interpret(),
-                                     primary=primary, overlap=overlap)
+        return kfd.fused_dispatch_pallas(pools, zero_blocks, cmds,
+                                         block_axis=block_axis,
+                                         interpret=_interpret(),
+                                         primary=primary, overlap=overlap)
     out = _fused_ref_jit(cmds, tuple(zero_blocks), tuple(pools),
                          block_axis=block_axis, primary=primary)
-    notify_launch(int(cmds.shape[0]), len(out), "fused")
+    kfd.notify_launch(int(cmds.shape[0]), len(out), "fused")
     return tuple(out)
 
 
@@ -113,11 +110,10 @@ def fused_dispatch_sharded(pools, zero_blocks, plan, *, mesh, pool_axes,
     reference elsewhere; the inter-slab hops are ppermute collectives
     either way.  ``primary`` as in :func:`fused_dispatch`; ``replicated``
     marks pools held whole on every device (must match the plan)."""
-    return sharded_fused_dispatch(pools, zero_blocks, plan, mesh=mesh,
-                                  pool_axes=pool_axes, block_axis=block_axis,
-                                  use_pallas=_resolve_use_pallas(use_pallas),
-                                  interpret=_interpret(),
-                                  primary=primary, replicated=replicated)
+    return kfd.sharded_fused_dispatch(
+        pools, zero_blocks, plan, mesh=mesh, pool_axes=pool_axes,
+        block_axis=block_axis, use_pallas=_resolve_use_pallas(use_pallas),
+        interpret=_interpret(), primary=primary, replicated=replicated)
 
 
 def baseline_copy(pool, ids):

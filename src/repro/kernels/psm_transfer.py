@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import axis_size, tpu_compiler_params
 
 PIPELINE_DEPTH = 2
 
@@ -46,7 +45,7 @@ def _psm_kernel(ids_ref, src_ref, _dst_in, dst_ref, send_sems, recv_sems, *,
     dst = ids_ref[i, 1]
     hop = ids_ref[i, 2]
     my = jax.lax.axis_index(axis_name)
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     target = jax.lax.rem(my + hop + n, n)
     slot = jax.lax.rem(i, PIPELINE_DEPTH)
 
@@ -86,5 +85,5 @@ def psm_transfer_pallas(pool_slab, ids, *, axis_name: str = "model"):
         ),
         out_shape=jax.ShapeDtypeStruct(pool_slab.shape, pool_slab.dtype),
         input_output_aliases={2: 0},
-        compiler_params=tpu_compiler_params(collective_id=13),
+        compiler_params=pltpu.CompilerParams(collective_id=13),
     )(ids, pool_slab, pool_slab)

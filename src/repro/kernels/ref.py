@@ -74,13 +74,19 @@ def fused_dispatch(pools, zero_blocks, cmds, block_axis=0, primary=None):
     same global-id space (``total`` = sum of the pool block counts;
     ``OP_NOT`` packs ``b == a``) — and a *global-id* dst, so fingerprint
     rows can land in staging pools.  Sources are gathered from the
-    pre-flush state and combined through a same-width unsigned-int
-    bitcast, so float pools AND/OR/NOT their raw bit patterns."""
+    pre-flush state and combined as raw bits, so float pools AND/OR/NOT
+    their bit patterns."""
     from repro.core.opcodes import (BITWISE_OPS, OP_AND, OP_CROSS_POOL_COPY,
                                     OP_OR, OP_ZERO_INIT)
     from repro.kernels.fused_dispatch import (_as_primary, _bitcast_uint,
                                               _op_in)
-    pools = list(pools)
+    # every select and scatter below moves raw bit patterns: a float
+    # select may flush subnormals or requiet NaNs (the bitwise rows make
+    # both) where a backend widens 16-bit floats, and the oracle must move
+    # bytes exactly as the DMA drain does
+    dtypes = [p.dtype for p in pools]
+    pools = [_bitcast_uint(p) for p in pools]
+    zero_blocks = [_bitcast_uint(z) for z in zero_blocks]
     n = len(pools)
     primary = _as_primary(primary, n)
     ba = block_axis
@@ -161,11 +167,10 @@ def fused_dispatch(pools, zero_blocks, cmds, block_axis=0, primary=None):
                 (pool.shape[0], cmds.shape[0]) + pool.shape[2:])
         rows = jnp.where(expand(op == OP_ZERO_INIT, rows), zrows, rows)
         # bitwise compute rows: combine both sources bit-for-bit
-        au = _bitcast_uint(gather_global(a_loc, a_in, pd))
-        bu = _bitcast_uint(gather_global(b_loc, b_in, pd))
-        ru = jnp.where(expand(op == OP_AND, au), au & bu,
-                       jnp.where(expand(op == OP_OR, au), au | bu, ~au))
-        brows = jax.lax.bitcast_convert_type(ru, pool.dtype)
+        au = gather_global(a_loc, a_in, pd)
+        bu = gather_global(b_loc, b_in, pd)
+        brows = jnp.where(expand(op == OP_AND, au), au & bu,
+                          jnp.where(expand(op == OP_OR, au), au | bu, ~au))
         rows = jnp.where(expand(is_bitwise, rows), brows, rows)
         if primary[pd]:
             valid = (op >= 0) & (d >= 0) & (~glb_dst | d_in[pd])
@@ -174,7 +179,8 @@ def fused_dispatch(pools, zero_blocks, cmds, block_axis=0, primary=None):
         safe = jnp.where(valid, d_loc, sizes[pd])
         out.append(pool.at[safe].set(rows, mode="drop") if ba == 0
                    else pool.at[:, safe].set(rows, mode="drop"))
-    return tuple(out)
+    return tuple(jax.lax.bitcast_convert_type(o, dt)
+                 for o, dt in zip(out, dtypes))
 
 
 # ---------------------------------------------------------------------------

@@ -46,12 +46,20 @@ directly) for A/B benchmarking — ``benchmarks/bench_dispatch.py
 serve_round`` and the staging parity suite drive both.
 
 CLI:  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b \
-          --smoke --requests 8 --steps 32 --fork 2
+          --requests 8 --steps 32 --fork 2 [--full]
+
+Without ``--full`` the CLI serves the config's reduced smoke variant;
+with it, the published widths in the config's dtype (bf16 for
+llama3.2-3b: 6.4 GB of weights and 1.9 GB of KV pools, which fits one
+16 GB TPU v5e).  Weights are random, drawn from ``--seed``.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
+import os
+import pathlib
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -307,6 +315,8 @@ class ServingEngine:
         # un-donated scatter paid per admission.
         self._prefill_stage_jit = jax.jit(self._prefill_stage_fn,
                                           donate_argnums=(2, 3))
+        self._prefill_jit = jax.jit(functools.partial(
+            self.model.prefill, mesh=mesh, margin_tokens=0))
         # the round's bulk movement lives on a dedicated CommandStream:
         # admissions/forks CAPTURE their promotions and CoW work onto it,
         # and decode_round's stream.flush() drains everything as one
@@ -489,8 +499,9 @@ class ServingEngine:
             self._pending_promotions[sid] = pairs
             st = extras
         else:
-            logits, st = self.model.prefill(self.params, batch, self.mesh,
-                                            margin_tokens=0)
+            # jitted like the fused path's prefill: an eager prefill rounds
+            # differently and can flip a near-tie greedy argmax
+            logits, st = self._prefill_jit(self.params, batch)
             # seed path: one ad-hoc gather/scatter dispatch per pool,
             # bypassing the command queue (kept for A/B)
             dst = jnp.asarray(np.asarray(blocks, np.int32))
@@ -911,6 +922,75 @@ def _stage_legacy(pool, staging, dst_ids):
     return pool.at[:, safe].set(staging.astype(pool.dtype), mode="drop")
 
 
+#: pool sizing shared by the CLI and the chip smoke: 16 live sequences of
+#: up to 16 pages (1024 tokens) each.  The staging ring derives from it
+#: (one admission of ``SERVE_BLOCKS_PER_SEQ`` pages per round).
+SERVE_MAX_SEQS = 16
+SERVE_BLOCKS_PER_SEQ = 16
+
+
+def setup_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory and
+    return it: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads the
+    variable itself, so nothing else is set), else ``.jax_cache`` at the
+    root of the checkout.  Entry points call this at start-up; importing
+    the library never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(pathlib.Path(__file__).resolve().parents[3]
+                   / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_params(cfg, seed: int, sharding=None):
+    """Random weights for ``cfg`` from ``seed``, in ``cfg.dtype``.  The
+    init is jitted and casts inside, so a full-width fp32 tree (12.8 GB
+    for llama3.2-3b) never exists on the device.  ``sharding`` places
+    every leaf (e.g. replicated over a serving mesh)."""
+    model = build_model(cfg)
+    dtype = jnp.dtype(cfg.dtype)
+
+    def init(key):
+        params, _ = split_params(model.init_params(key))
+        return jax.tree_util.tree_map(lambda x: x.astype(dtype), params)
+
+    return jax.jit(init, out_shardings=sharding)(jax.random.key(seed))
+
+
+def build_engine(arch: str, *, full: bool = False, seed: int = 0,
+                 mesh=None, **engine_kw) -> ServingEngine:
+    """The serving engine the CLI and ``chip_smoke.py`` run: ``arch`` at
+    its published widths (``full``) or its reduced smoke variant, random
+    weights from ``seed`` (replicated over ``mesh`` when given), and
+    explicitly sized pools.  ``engine_kw`` passes through to
+    :class:`ServingEngine`."""
+    cfg = get_config(arch)
+    if not full:
+        cfg = cfg.reduced()
+    sharding = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+        sharding = NamedSharding(mesh, PartitionSpec())
+    params = init_params(cfg, seed, sharding)
+    return ServingEngine(cfg, params, mesh=mesh, max_seqs=SERVE_MAX_SEQS,
+                         max_blocks_per_seq=SERVE_BLOCKS_PER_SEQ, **engine_kw)
+
+
+def print_memory_report(eng: ServingEngine, tag: str = "serve") -> None:
+    """Print the bytes the engine holds on the device: the weights, and
+    each pool with its shape."""
+    params = sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.params))
+    print(f"[{tag}] params: {params} bytes ({eng.cfg.arch_id}, "
+          f"{eng.cfg.dtype})")
+    for name, pool in eng.engine.pools.items():
+        print(f"[{tag}] pool {name}: {tuple(pool.shape)} {pool.dtype} = "
+              f"{pool.nbytes} bytes")
+    print(f"[{tag}] pools total: {eng.engine.pool_bytes_resident()} bytes "
+          f"({eng.cache.max_seqs} seqs x {eng.cache.max_blocks_per_seq} "
+          f"blocks, {eng.engine.stage_capacity} staging slots)")
+
+
 def main():
     """CLI: admit random prompts, optionally fork, greedy-decode, and
     print the RowClone mechanism stats (see the module docstring)."""
@@ -920,7 +1000,11 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--fork", type=int, default=0)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", action="store_true",
+                    help="published widths in the config's dtype "
+                         "(default: the reduced smoke variant)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--staging-ring", type=int, default=-1,
                     help="staging slots (max_admit_pages): size staging "
                          "as a recycled ring instead of full KV twins "
@@ -932,20 +1016,14 @@ def main():
                          "at 1.0 launches/round")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.reduced()
-    model = build_model(cfg)
-    params, _ = split_params(model.init_params(jax.random.key(0)))
-    eng = ServingEngine(cfg, params, max_seqs=max(args.requests * 4, 8),
-                        max_admit_pages=(None if args.staging_ring < 0
-                                         else args.staging_ring),
-                        double_buffer=args.double_buffer)
-    print(f"[serve] resident pool bytes: "
-          f"{eng.engine.pool_bytes_resident() / 1e6:.1f} MB "
-          f"(staging slots: {eng.engine.stage_capacity} of "
-          f"{eng.engine.num_blocks} KV blocks)")
-    rng = np.random.default_rng(0)
+    setup_compile_cache()
+    eng = build_engine(args.arch, full=args.full, seed=args.seed,
+                       max_admit_pages=(None if args.staging_ring < 0
+                                        else args.staging_ring),
+                       double_buffer=args.double_buffer)
+    cfg = eng.cfg
+    print_memory_report(eng)
+    rng = np.random.default_rng(args.seed)
     sids = []
     for i in range(args.requests):
         p = rng.integers(2, cfg.vocab_size, size=args.prompt_len)

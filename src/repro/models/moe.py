@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.common import Param, dense_init, init_mlp, swiglu_mlp
 from repro.sharding import constrain
@@ -164,7 +163,7 @@ def _moe_ffn_fsdp(params, x, cfg: ModelConfig, mesh):
         return y, jax.lax.pmean(aux, all_axes)
 
     shared = params.get("shared")
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(), P(), P(),
                   None if shared is None else jax.tree_util.tree_map(
@@ -263,7 +262,7 @@ def _moe_ffn_a2a(params, x, cfg: ModelConfig, mesh):
         aux = jax.lax.psum(aux_l + 1e-3 * z_l, dp + (tp,)) / n_dev
         return y, aux
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(tp), P(tp), P(tp), P(bspec, tp, None)),
         out_specs=(P(bspec, tp, None), P()),

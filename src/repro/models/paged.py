@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import axis_size as compat_axis_size, shard_map
 from repro.models.attention import lse_combine, paged_attention_slab
 
 
@@ -234,7 +233,7 @@ def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
     fn = functools.partial(_attend_append_local, combine=comb,
                            pool_axes=pool_shard_axes(mesh), page=page,
                            impl=impl, exclusive=exclusive)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec), pspec, pspec,
                   P(bspec), P(bspec), mspec, pspec, P(bspec)),
@@ -275,7 +274,7 @@ def _slab_offset(pool_axes: Tuple[str, ...], slab: int):
     block dimension *in shard order*."""
     idx = jnp.int32(0)
     for a in pool_axes:
-        idx = idx * compat_axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx * slab
 
 

@@ -81,12 +81,11 @@ def tuned_dir() -> pathlib.Path:
 
 def backend_key() -> str:
     """The profile key for this process: ``jax.default_backend()``
-    ("cpu", "tpu", "gpu"); "cpu" when jax is unavailable."""
-    try:
-        import jax
-        return str(jax.default_backend())
-    except Exception:
-        return "cpu"
+    ("cpu", "tpu", "gpu").  A backend that cannot be queried raises:
+    pretending to be the CPU would load the CPU's tuned constants on
+    another device."""
+    import jax
+    return str(jax.default_backend())
 
 
 def profile_path(backend: Optional[str] = None,

@@ -307,7 +307,6 @@ def test_psm_rdma_kernel_traces_on_multidevice_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
         from repro.kernels.psm_transfer import psm_transfer_pallas
         mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
                     ("data", "model"))
@@ -316,7 +315,7 @@ def test_psm_rdma_kernel_traces_on_multidevice_mesh():
                                                    axis_name="model")
         with mesh:
             out = jax.eval_shape(
-                lambda p, i: shard_map(
+                lambda p, i: jax.shard_map(
                     local, mesh=mesh, in_specs=(P("model"), P(None)),
                     out_specs=P("model"), check_vma=False)(p, i),
                 jax.ShapeDtypeStruct((32, 16, 128), jnp.float32),
