@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.launch.serve import ServingEngine
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,8 +105,6 @@ class RoundReport:
     resumed: List[int]           #: rids resumed this round
     tokens: Dict[str, int]       #: decode tokens per tenant this round
     round_us: float = 0.0        #: this round's wall-clock (step() span)
-    p50_round_us: float = 0.0    #: running median over rounds so far
-    p99_round_us: float = 0.0    #: running p99 over rounds so far
 
 
 class _Lane:
@@ -145,7 +144,6 @@ class RequestScheduler:
         self._next_rid = 0
         self.round_index = 0
         self.reports: List[RoundReport] = []
-        self._round_us: List[float] = []   # per-round wall-clock history
 
     # ------------------------------------------------------------------
     @property
@@ -276,8 +274,9 @@ class RequestScheduler:
                         self._blocks_needed(len(req.prompt))):
                     break
                 lane.queued.popleft()
-                req.sid = self.eng.add_request(req.prompt,
-                                               stream=lane.stream)
+                with span("engine.prefill", rid=req.rid):
+                    req.sid = self.eng.add_request(req.prompt,
+                                                   stream=lane.stream)
                 self._by_sid[req.sid] = req.rid
                 req.state = "running"
                 self._running.append(req.rid)
@@ -338,31 +337,47 @@ class RequestScheduler:
     # ------------------------------------------------------------------
     def step(self, sample_fn=None) -> RoundReport:
         """Run ONE continuous-batching round (see the module docstring
-        for the five stages) and return its :class:`RoundReport` —
-        timed with the shared obs stopwatch, carrying the running
-        p50/p99 round latency."""
-        with obs_metrics.Stopwatch() as sw:
-            finished = self._retire_finished()
-            admitted, resumed = self._admit_and_resume()
-            preempted = self._preempt_for_waiters()
+        for the five stages) and return its :class:`RoundReport`, timed
+        with the shared obs stopwatch.  The round is a ``sched.step``
+        span whose stages (``sched.retire``, ``sched.admit``,
+        ``sched.preempt``, ``sched.adopt``, ``engine.decode_round``,
+        ``sched.report``) cover it end to end."""
+        with span("sched.step", round=self.round_index), \
+                obs_metrics.Stopwatch() as sw:
+            with span("sched.retire"):
+                finished = self._retire_finished()
+            with span("sched.admit"):
+                admitted, resumed = self._admit_and_resume()
+            with span("sched.preempt"):
+                preempted = self._preempt_for_waiters()
             # lane merge: adopt every lane's pending rows onto the serve
             # stream in priority order — one flush, one launch, priority
             # traffic first in the fused table
-            for lane in self.lanes.values():
-                self.eng.stream.adopt(lane.stream)
+            with span("sched.adopt"):
+                for lane in self.lanes.values():
+                    self.eng.stream.adopt(lane.stream)
             toks = self.eng.decode_round(sample_fn=sample_fn)
-            per_tenant: Dict[str, int] = {t: 0 for t in self.lanes}
-            for sid in toks:
-                rid = self._by_sid.get(sid)
-                if rid is None:
-                    continue
-                req = self.requests[rid]
-                req.generated += 1
-                req.tokens_out.append(int(toks[sid]))
-                if req.first_token_round < 0:
-                    req.first_token_round = self.round_index
-                per_tenant[req.tenant] += 1
-        self._round_us.append(sw.us)
+            with span("sched.report"):
+                return self._report(sw, toks, finished, admitted, resumed,
+                                    preempted)
+
+    def _report(self, sw, toks, finished, admitted, resumed,
+                preempted) -> RoundReport:
+        """The round's bookkeeping after its decode: per-request tokens,
+        lane counters and the :class:`RoundReport`.  ``round_us`` is the
+        round's running stopwatch read once its tokens are accounted."""
+        per_tenant: Dict[str, int] = {t: 0 for t in self.lanes}
+        for sid in toks:
+            rid = self._by_sid.get(sid)
+            if rid is None:
+                continue
+            req = self.requests[rid]
+            req.generated += 1
+            req.tokens_out.append(int(toks[sid]))
+            if req.first_token_round < 0:
+                req.first_token_round = self.round_index
+            per_tenant[req.tenant] += 1
+        round_us = sw.us
         if obs_metrics.metrics_enabled():
             # per-lane lifecycle counters, labeled by tenant
             for rid_list, what in ((admitted, "admitted"),
@@ -375,7 +390,7 @@ class RequestScheduler:
             for tenant, n in per_tenant.items():
                 if n:
                     obs_metrics.inc("lane.tokens", n, tenant=tenant)
-            obs_metrics.observe("sched.round_us", sw.us)
+            obs_metrics.observe("sched.round_us", round_us)
         ticket = self.eng.last_ticket
         report = RoundReport(
             round_index=self.round_index,
@@ -383,9 +398,7 @@ class RequestScheduler:
             commands=ticket.commands if ticket is not None else 0,
             admitted=admitted, finished=finished,
             preempted=preempted, resumed=resumed, tokens=per_tenant,
-            round_us=sw.us,
-            p50_round_us=obs_metrics.percentile(self._round_us, 50),
-            p99_round_us=obs_metrics.percentile(self._round_us, 99))
+            round_us=round_us)
         self.reports.append(report)
         self.round_index += 1
         return report

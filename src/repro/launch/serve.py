@@ -76,6 +76,7 @@ from repro.models import build_model, split_params
 from repro.models.paged import batch_shard_count, make_serving_pools
 from repro.obs import metrics as obs_metrics
 from repro.obs.autotune import load_profile
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -427,7 +428,24 @@ class ServingEngine:
         stream instead of the engine's serve stream — the scheduler's
         per-tenant QoS lanes admit here and
         :meth:`~repro.core.stream.CommandStream.adopt` their rows into
-        the round stream in priority order."""
+        the round stream in priority order.
+
+        Spans: ``engine.prefill_dispatch`` (sequence, batch, staging
+        reservation, the jitted prefill) then ``engine.prefill_logits``
+        (the blocking copy of the last position's logits)."""
+        with span("engine.prefill_dispatch"):
+            sid, logits, st = self._dispatch_prefill(prompt, stream)
+        with span("engine.prefill_logits"):
+            self.last_logits[sid] = np.asarray(logits[0])
+        self.tokens[sid] = [int(t) for t in prompt]
+        # extra per-seq state (ssm/hybrid/encdec) kept host-side per slot
+        self._store_extra_state(sid, st)
+        return sid
+
+    def _dispatch_prefill(self, prompt: np.ndarray, stream=None):
+        """:meth:`add_request` up to its logits: a new sequence, the
+        prefill dispatched (staged and promoted through ``stream``, or
+        scattered on the seed path); returns (sid, logits, extra state)."""
         stream = self.stream if stream is None else stream
         S = int(prompt.shape[0])
         if self.fused_staging:
@@ -512,11 +530,7 @@ class ServingEngine:
             self.engine.pools["v"] = _stage_legacy(  # rowlint: disable=RC103
                 self.engine.pools["v"], st["v_pools"], dst)
             notify_launch(len(blocks), 1, "legacy_stage")
-        self.last_logits[sid] = np.asarray(logits[0])
-        self.tokens[sid] = [int(t) for t in prompt]
-        # extra per-seq state (ssm/hybrid/encdec) kept host-side per slot
-        self._store_extra_state(sid, st)
-        return sid
+        return sid, logits, st
 
     def _dedup_pages(self, sid: int, prompt: np.ndarray,
                      stage_ids: List[int],
@@ -827,11 +841,19 @@ class ServingEngine:
         return logits, st["k_pools"], st["v_pools"]
 
     def decode_round(self, sample_fn=None) -> Dict[int, int]:
-        """One token for every live sequence (greedy by default)."""
+        """One token for every live sequence (greedy by default), in an
+        ``engine.decode_round`` span whose stages run in order:
+        ``engine.argmax``, ``engine.cow_append``, ``flush`` (``drain``),
+        ``engine.table_upload``, ``engine.decode_dispatch``,
+        ``engine.logits_fetch``, ``engine.post_decode``."""
         if self.cfg.family not in ("dense", "moe", "vlm"):
             raise NotImplementedError(
                 "CLI decode loop demo targets decoder-only archs; other "
                 "families decode through model.decode_step directly")
+        with span("engine.decode_round"):
+            return self._decode_round(sample_fn)
+
+    def _decode_round(self, sample_fn) -> Dict[int, int]:
         live = sorted(self.cache.seqs)
         if not live:
             # still drain pending bulk movement (e.g. every sequence was
@@ -847,20 +869,22 @@ class ServingEngine:
                 self._post_flush()
             return {}
         # choose next token per sequence from last logits
-        next_tok = {}
-        for sid in live:
-            lg = self.last_logits[sid]
-            t = int(np.argmax(lg)) if sample_fn is None else sample_fn(lg)
-            next_tok[sid] = t
+        with span("engine.argmax"):
+            next_tok = {}
+            for sid in live:
+                lg = self.last_logits[sid]
+                next_tok[sid] = int(np.argmax(lg)) if sample_fn is None \
+                    else sample_fn(lg)
         # CoW/allocation happens BEFORE the jit step (host metadata); the
         # round's staged-prefill promotions + CoW splits + tail-block
         # inits all drain as ONE fused launch at this stream flush —
         # the FlushTicket records the round's launch accounting
-        if self.fused_staging:
-            with self.stream.capture():
-                self.cache.append_tokens(live)
-        else:
-            self.cache.append_tokens(live)   # seed path: eager per-call
+        with span("engine.cow_append"):
+            if self.fused_staging:
+                with self.stream.capture():
+                    self.cache.append_tokens(live)
+            else:
+                self.cache.append_tokens(live)   # seed path: eager per-call
         try:
             self.last_ticket = self.stream.flush()
         except Exception:
@@ -876,40 +900,43 @@ class ServingEngine:
             if not live:
                 return {}
         self._post_flush()
-        table, mask, base = self.cache.device_tables()
-        lens = self.cache.seq_lens()
-        B = self.cache.max_seqs
-        toks = np.zeros((B,), np.int32)
-        seq_lens_dev = np.zeros((B,), np.int32)
-        for sid in live:
-            slot = self.cache.slot_of(sid)
-            toks[slot] = next_tok[sid]
-            # decode_step's pos = state.seq_lens = position of new token
-            seq_lens_dev[slot] = self.cache.seqs[sid].length - 1
-        logits, kp, vp = self._decode_jit(
-            self.params, self.engine.pools["k"], self.engine.pools["v"],
-            table, mask, base, jnp.asarray(seq_lens_dev), jnp.asarray(toks),
-            None)
-        # out-of-band decode-step append (reproduced by re-running the
-        # producer on recovery, never by journal replay)
-        self.engine.pools["k"] = kp  # rowlint: disable=RC103
-        self.engine.pools["v"] = vp  # rowlint: disable=RC103
-        logits = np.asarray(logits)
-        for sid in live:
-            slot = self.cache.slot_of(sid)
-            self.last_logits[sid] = logits[slot]
-            self.tokens[sid].append(next_tok[sid])
-        if self.pool_ckpt is not None:
-            # one background checkpoint window per round: spill-pool
-            # cross-copies on the ckpt stream, harvested next round (the
-            # ticket's write-scoped wait never blocks on the KV pools
-            # this round's decode just donated)
-            try:
-                self.pool_ckpt.step()
-            except Exception:
-                if not self.auto_recover:
-                    raise
-                self.recover()
+        with span("engine.table_upload"):
+            table, mask, base = self.cache.device_tables()
+        with span("engine.decode_dispatch"):
+            B = self.cache.max_seqs
+            toks = np.zeros((B,), np.int32)
+            seq_lens_dev = np.zeros((B,), np.int32)
+            for sid in live:
+                slot = self.cache.slot_of(sid)
+                toks[slot] = next_tok[sid]
+                # decode_step's pos = state.seq_lens = position of new token
+                seq_lens_dev[slot] = self.cache.seqs[sid].length - 1
+            logits, kp, vp = self._decode_jit(
+                self.params, self.engine.pools["k"], self.engine.pools["v"],
+                table, mask, base, jnp.asarray(seq_lens_dev),
+                jnp.asarray(toks), None)
+            # out-of-band decode-step append (reproduced by re-running the
+            # producer on recovery, never by journal replay)
+            self.engine.pools["k"] = kp  # rowlint: disable=RC103
+            self.engine.pools["v"] = vp  # rowlint: disable=RC103
+        with span("engine.logits_fetch"):
+            logits = np.asarray(logits)
+        with span("engine.post_decode"):
+            for sid in live:
+                slot = self.cache.slot_of(sid)
+                self.last_logits[sid] = logits[slot]
+                self.tokens[sid].append(next_tok[sid])
+            if self.pool_ckpt is not None:
+                # one background checkpoint window per round: spill-pool
+                # cross-copies on the ckpt stream, harvested next round
+                # (the ticket's write-scoped wait never blocks on the KV
+                # pools this round's decode just donated)
+                try:
+                    self.pool_ckpt.step()
+                except Exception:
+                    if not self.auto_recover:
+                        raise
+                    self.recover()
         return next_tok
 
 

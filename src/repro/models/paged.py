@@ -190,7 +190,9 @@ def make_serving_pools(num_layers: int, nblk: int, page: int, kv_heads: int,
 def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
                         blk_ids, offsets, share_mask, base, seq_lens,
                         impl: str = "ref", exclusive: bool = False):
-    """Append this step's K/V then attend over the paged cache.
+    """Append this step's K/V then attend over the paged cache, under the
+    ``decode_attention`` named scope (its ops carry that scope in a device
+    trace).
 
     q:        (B, H, D)      new-token queries, post-RoPE
     k_new/v_new: (B, KVH, D) new-token keys/values, post-RoPE
@@ -210,38 +212,40 @@ def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
 
     Returns (out (B,H,D), k_pool', v_pool').
     """
-    page = k_pool.shape[1]
-    if mesh is None or int(np.prod(mesh.devices.shape)) == 1:
-        return _attend_append_local(q, k_new, v_new, k_pool, v_pool, blk_ids,
-                                    offsets, share_mask, base, seq_lens,
-                                    page=page, impl=impl,
-                                    exclusive=exclusive)
+    with jax.named_scope("decode_attention"):
+        page = k_pool.shape[1]
+        if mesh is None or int(np.prod(mesh.devices.shape)) == 1:
+            return _attend_append_local(q, k_new, v_new, k_pool, v_pool,
+                                        blk_ids, offsets, share_mask, base,
+                                        seq_lens, page=page, impl=impl,
+                                        exclusive=exclusive)
 
-    B = q.shape[0]
-    b_axes = batch_shard_axes(mesh, B)
-    dp = int(np.prod([mesh.shape[a] for a in b_axes])) if b_axes else 1
-    if b_axes and share_mask.shape[1] != B // dp:
-        # mask columns are GLOBAL batch numbering: the caller's placement
-        # isn't group-aligned, so replicate the batch instead of sharding
-        # it (every slab serves every sequence; combine spans all axes)
-        b_axes = ()
-    bspec = _maybe(b_axes)
-    pspec = pool_spec(mesh)
-    mspec = P(pspec[0], None)
-    comb = combine_axes(mesh, b_axes)
+        B = q.shape[0]
+        b_axes = batch_shard_axes(mesh, B)
+        dp = int(np.prod([mesh.shape[a] for a in b_axes])) if b_axes else 1
+        if b_axes and share_mask.shape[1] != B // dp:
+            # mask columns are GLOBAL batch numbering: the caller's
+            # placement isn't group-aligned, so replicate the batch instead
+            # of sharding it (every slab serves every sequence; combine
+            # spans all axes)
+            b_axes = ()
+        bspec = _maybe(b_axes)
+        pspec = pool_spec(mesh)
+        mspec = P(pspec[0], None)
+        comb = combine_axes(mesh, b_axes)
 
-    fn = functools.partial(_attend_append_local, combine=comb,
-                           pool_axes=pool_shard_axes(mesh), page=page,
-                           impl=impl, exclusive=exclusive)
-    mapped = jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(P(bspec), P(bspec), P(bspec), pspec, pspec,
-                  P(bspec), P(bspec), mspec, pspec, P(bspec)),
-        out_specs=(P(bspec), pspec, pspec),
-        check_vma=False,
-    )
-    return mapped(q, k_new, v_new, k_pool, v_pool, blk_ids, offsets,
-                  share_mask, base, seq_lens)
+        fn = functools.partial(_attend_append_local, combine=comb,
+                               pool_axes=pool_shard_axes(mesh), page=page,
+                               impl=impl, exclusive=exclusive)
+        mapped = jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(P(bspec), P(bspec), P(bspec), pspec, pspec,
+                      P(bspec), P(bspec), mspec, pspec, P(bspec)),
+            out_specs=(P(bspec), pspec, pspec),
+            check_vma=False,
+        )
+        return mapped(q, k_new, v_new, k_pool, v_pool, blk_ids, offsets,
+                      share_mask, base, seq_lens)
 
 
 def _attend_append_local(q, k_new, v_new, k_slab, v_slab, blk_ids, offsets,

@@ -6,11 +6,11 @@ Three pieces:
   with labeled series (stream, opcode, pool, tenant lane), plus the one
   sanctioned timing clock and the shared timer/percentile helpers every
   benchmark uses (rowlint RC105 enforces the monopoly).
-* :mod:`repro.obs.trace` — named spans over the flush lifecycle
-  (``flush -> drain -> ticket-wait``): ``jax.profiler`` trace sections
-  when a profile is active, wall-clock :class:`~repro.obs.trace.Span`
-  records always; :class:`~repro.obs.trace.FlushTiming` rides on
-  ``FlushTicket.timing``.
+* :mod:`repro.obs.trace` — named spans over the serving round and the
+  flush lifecycle (``sched.step`` and its stages, ``flush -> drain``,
+  ``ticket-wait``): ``jax.profiler`` trace sections always, wall-clock
+  :class:`~repro.obs.trace.Span` records when recording is on;
+  :class:`~repro.obs.trace.FlushTiming` rides on ``FlushTicket.timing``.
 * :mod:`repro.obs.autotune` — per-backend
   :class:`~repro.obs.autotune.TunedProfile` (JSON under
   ``configs/tuned/``) written by ``benchmarks/bench_autotune.py`` and

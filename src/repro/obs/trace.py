@@ -1,33 +1,47 @@
-"""Named spans over the flush lifecycle — wall-clock always, profiler
-sections when a profile is active.
+"""Named spans over the engine's hot path and the serving round.
 
-The engine's hot path is wrapped in nested spans
-(``enqueue -> flush -> drain -> ticket-wait``), Levanter-style: every
-span emits a ``jax.profiler.TraceAnnotation`` (a TraceMe — visible in a
-captured profile's timeline, near-free when no profile is active) AND
-appends a host-side :class:`Span` record with wall-clock start/end and
-its nesting depth, so span data exists even without a profiler attached.
+Every span is a ``jax.profiler.TraceAnnotation`` (a TraceMe): a section
+on a captured profile's host timeline, its labels attached as the
+event's stats, and next to free when no profile is active.  The serving
+round (``sched.step`` and its stages, ``engine.*``) and the flush
+lifecycle (``flush -> drain``, ``ticket-wait``) are spanned in the
+program, so a device trace can charge every idle gap on the chip to the
+host stage that kept it waiting.
 
-Records live in a bounded ring (:func:`spans` reads, :func:`reset_spans`
-clears).  :class:`FlushTiming` is the per-flush timing quad the engine
-stashes and ``FlushTicket.timing`` carries: queue residency (first
-enqueue -> flush call), drain wall-clock, bucket-padded table length,
-and launches.
+With recording on (:func:`set_tracing`; off by default) each span also
+leaves a wall-clock :class:`Span` record in a bounded ring
+(:func:`spans` reads, :func:`span_tree` nests, :func:`reset_spans`
+clears).  Records carry absolute sequence numbers and link to their
+parent by that number, so dropping the oldest record is O(1).
+With recording off a span is the annotation alone: no record, no
+allocation beyond the TraceMe.
+
+:class:`FlushTiming` is the per-flush timing quad the engine stashes and
+``FlushTicket.timing`` carries: queue residency (first enqueue -> flush
+call), drain wall-clock, bucket-padded table length, and launches.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 from repro.obs.metrics import now
+
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+except Exception:       # profiler unavailable: records only
+    def _Annotation(name, **labels):
+        return contextlib.nullcontext()
 
 #: bounded span-record ring size (oldest records drop past this)
 MAX_SPANS = 4096
 
-_RECORDS: List["Span"] = []
-_STACK: List[int] = []
-_ENABLED = True
+_RECORDS: Deque["Span"] = collections.deque(maxlen=MAX_SPANS)
+_STACK: List["Span"] = []
+_NEXT = 0               # sequence number of the next record
+_RECORDING = False
 
 
 @dataclasses.dataclass
@@ -38,8 +52,9 @@ class Span:
     start: float                   #: perf_counter seconds at entry
     end: float                     #: perf_counter seconds at exit
     depth: int                     #: nesting depth (0 = root)
-    parent: int                    #: index of the enclosing span, -1 = root
+    parent: int                    #: ``seq`` of the enclosing span, -1 = root
     labels: Tuple[Tuple[str, str], ...] = ()   #: sorted label pairs
+    seq: int = -1                  #: absolute sequence number (0 = first)
 
     @property
     def us(self) -> float:
@@ -59,44 +74,45 @@ class FlushTiming:
     launches: int                  #: device launches the flush issued
 
 
-def _annotation(name: str):
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:       # profiler unavailable: wall-clock only
-        return contextlib.nullcontext()
+class _Recorded:
+    """A span with recording on: the annotation plus a :class:`Span`."""
+
+    __slots__ = ("ann", "rec")
+
+    def __init__(self, ann, name: str, labels: Dict) -> None:
+        self.ann = ann
+        self.rec = Span(name=name, start=0.0, end=0.0, depth=0, parent=-1,
+                        labels=tuple(sorted((k, str(v))
+                                            for k, v in labels.items())))
+
+    def __enter__(self) -> "_Recorded":
+        global _NEXT
+        rec = self.rec
+        rec.depth = len(_STACK)
+        rec.parent = _STACK[-1].seq if _STACK else -1
+        rec.seq = _NEXT
+        _NEXT += 1
+        _RECORDS.append(rec)
+        _STACK.append(rec)
+        self.ann.__enter__()
+        rec.start = now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.rec.end = now()
+        self.ann.__exit__(*exc)
+        if _STACK and _STACK[-1] is self.rec:
+            _STACK.pop()
 
 
-@contextlib.contextmanager
-def span(name: str, **labels) -> Iterator[None]:
-    """Open a named span: a ``jax.profiler.TraceAnnotation`` section when
-    a profile is active, and a wall-clock :class:`Span` record always
-    (bounded ring; see :func:`spans`).  Spans nest — the record keeps
-    its depth and parent index, so capture/adopt call trees are visible
-    in the record list."""
-    if not _ENABLED:
-        yield
-        return
-    parent = _STACK[-1] if _STACK else -1
-    depth = len(_STACK)
-    idx = len(_RECORDS)
-    rec = Span(name=name, start=now(), end=0.0, depth=depth, parent=parent,
-               labels=tuple(sorted((k, str(v)) for k, v in labels.items())))
-    _RECORDS.append(rec)
-    _STACK.append(idx)
-    try:
-        with _annotation(name):
-            yield
-    finally:
-        rec.end = now()
-        _STACK.pop()
-        if len(_RECORDS) > MAX_SPANS:
-            drop = len(_RECORDS) - MAX_SPANS
-            del _RECORDS[:drop]
-            # re-anchor parent indices after the ring dropped a prefix
-            for r in _RECORDS:
-                r.parent = r.parent - drop if r.parent >= drop else -1
-            _STACK[:] = [i - drop for i in _STACK if i >= drop]
+def span(name: str, **labels):
+    """A named span to use as ``with span("flush", stream=s): ...``: a
+    ``jax.profiler.TraceAnnotation`` always (``labels`` become the trace
+    event's stats), and with recording on (:func:`set_tracing`) a
+    wall-clock :class:`Span` record too.  Recorded spans nest: each
+    keeps its depth and its parent's sequence number."""
+    ann = _Annotation(name, **labels)
+    return _Recorded(ann, name, labels) if _RECORDING else ann
 
 
 def spans(name: Optional[str] = None) -> List[Span]:
@@ -107,38 +123,41 @@ def spans(name: Optional[str] = None) -> List[Span]:
 
 
 def reset_spans() -> None:
-    """Clear the span record ring (test isolation)."""
+    """Clear the span record ring and restart sequence numbers at 0."""
+    global _NEXT
     _RECORDS.clear()
     _STACK.clear()
+    _NEXT = 0
 
 
 def tracing_enabled() -> bool:
     """Is span recording currently on?"""
-    return _ENABLED
+    return _RECORDING
 
 
 def set_tracing(flag: bool) -> bool:
-    """Enable/disable span recording; returns the PREVIOUS state.  Off
-    skips both the record append and the profiler annotation — the
-    engine's behavior is unchanged either way (host-side only)."""
-    global _ENABLED
-    prev = _ENABLED
-    _ENABLED = bool(flag)
+    """Turn span recording on or off; returns the PREVIOUS state.  Off
+    (the default) keeps only the profiler annotation; either way the
+    engine's behavior is unchanged (host-side only)."""
+    global _RECORDING
+    prev = _RECORDING
+    _RECORDING = bool(flag)
     return prev
 
 
 def span_tree(records: Optional[List[Span]] = None) -> List[Dict]:
-    """Render span records as a nested dict tree (children inline) — the
-    debugging view of one round's ``flush -> drain`` hierarchy."""
-    records = _RECORDS if records is None else records
-    nodes = [{"name": r.name, "us": r.us, "labels": dict(r.labels),
-              "children": []} for r in records]
+    """Render span records as a nested dict tree (children inline, a
+    record whose parent dropped out of the ring becomes a root) — the
+    debugging view of one round's span hierarchy."""
+    records = list(_RECORDS) if records is None else records
+    nodes = {r.seq: {"name": r.name, "us": r.us, "labels": dict(r.labels),
+                     "children": []} for r in records}
     roots: List[Dict] = []
-    for i, r in enumerate(records):
-        if 0 <= r.parent < len(nodes):
-            nodes[r.parent]["children"].append(nodes[i])
+    for r in records:
+        if r.parent in nodes:
+            nodes[r.parent]["children"].append(nodes[r.seq])
         else:
-            roots.append(nodes[i])
+            roots.append(nodes[r.seq])
     return roots
 
 

@@ -40,14 +40,16 @@ from repro.obs.autotune import (TunedProfile, load_profile, pick_winner,
 
 @pytest.fixture(autouse=True)
 def _fresh_obs():
-    """Each test sees an empty registry/span ring and leaves metrics on."""
+    """Each test sees an empty registry/span ring with span recording on,
+    and leaves metrics on and recording as it found it."""
     obs.registry().reset()
     trace.reset_spans()
+    prev = trace.set_tracing(True)
     yield
     obs.registry().reset()
     trace.reset_spans()
     obs.set_metrics_enabled(True)
-    trace.set_tracing(True)
+    trace.set_tracing(prev)
 
 
 def mk_engine(seed=0, nblk=32, snblk=8, **kw):
@@ -169,6 +171,51 @@ def test_set_tracing_off_records_nothing():
         assert trace.spans() == []
     finally:
         trace.set_tracing(prev)
+
+
+def test_span_with_recording_off_leaves_no_record():
+    """Recording off: a span is the profiler annotation alone — the ring
+    stays empty and no sequence number is spent."""
+    trace.set_tracing(False)
+    with trace.span("engine.argmax"):
+        with trace.span("engine.prefill", rid=3):
+            pass
+    assert trace.spans() == []
+    trace.set_tracing(True)
+    with trace.span("after"):
+        pass
+    assert [(r.name, r.seq) for r in trace.spans()] == [("after", 0)]
+
+
+def test_ring_drop_keeps_parent_links_and_depth():
+    """Past MAX_SPANS records the oldest drop; every survivor's parent
+    still names its enclosing span by sequence number, and its depth is
+    unchanged (a parent that dropped out makes a root in the tree)."""
+    n_rounds = trace.MAX_SPANS // 3 + 10
+    for i in range(n_rounds):
+        with trace.span("sched.step", round=i):
+            with trace.span("engine.decode_round"):
+                with trace.span("engine.logits_fetch"):
+                    pass
+    recs = trace.spans()
+    assert len(recs) == trace.MAX_SPANS
+    assert recs[-1].seq == 3 * n_rounds - 1
+    by_seq = {r.seq: r for r in recs}
+    want = {"sched.step": (0, None), "engine.decode_round": (1, "sched.step"),
+            "engine.logits_fetch": (2, "engine.decode_round")}
+    for r in recs:
+        depth, parent = want[r.name]
+        assert r.depth == depth
+        if parent is None:
+            assert r.parent == -1
+        elif r.parent in by_seq:
+            assert by_seq[r.parent].name == parent
+            assert r.parent == r.seq - 1
+        else:
+            assert r.parent < recs[0].seq     # dropped with the oldest
+    last = trace.span_tree()[-1]
+    assert last["labels"] == {"round": str(n_rounds - 1)}
+    assert [c["name"] for c in last["children"]] == ["engine.decode_round"]
 
 
 # ---------------------------------------------------------------------------

@@ -181,6 +181,47 @@ def test_continuous_batching_single_launch_and_reclaim(served):
 
 
 @pytest.mark.slow
+def test_round_spans_cover_the_step(served):
+    """One round that admits a request and decodes, recorded: the
+    ``sched.step`` root holds the scheduler's stages in order, the
+    admission's ``engine.prefill`` carries its request id, and the
+    decode's stages (logits fetch, dispatch) sit under
+    ``engine.decode_round``."""
+    from repro.launch.scheduler import RequestScheduler, TenantSpec
+    from repro.obs import trace
+    cfg, params = served
+    eng = _sched_engine(cfg, params)
+    sched = RequestScheduler(eng, [TenantSpec("gold", 1)])
+    rid = sched.submit("gold", np.arange(2, 11, dtype=np.int32),
+                       max_new_tokens=2)
+    prev = trace.set_tracing(True)
+    trace.reset_spans()
+    try:
+        sched.step()
+        tree = trace.span_tree()
+    finally:
+        trace.set_tracing(prev)
+        trace.reset_spans()
+    steps = [n for n in tree if n["name"] == "sched.step"]
+    assert len(steps) == 1 and steps[0]["labels"] == {"round": "0"}
+    kids = steps[0]["children"]
+    assert [c["name"] for c in kids] == [
+        "sched.retire", "sched.admit", "sched.preempt", "sched.adopt",
+        "engine.decode_round", "sched.report"]
+    admit = kids[1]
+    assert [c["name"] for c in admit["children"]] == ["engine.prefill"]
+    prefill = admit["children"][0]
+    assert prefill["labels"] == {"rid": str(rid)}
+    assert [c["name"] for c in prefill["children"]] == [
+        "engine.prefill_dispatch", "engine.prefill_logits"]
+    decode = [c["name"] for c in kids[4]["children"]]
+    assert decode == ["engine.argmax", "engine.cow_append", "flush",
+                      "engine.table_upload", "engine.decode_dispatch",
+                      "engine.logits_fetch", "engine.post_decode"]
+    flush = kids[4]["children"][2]
+    assert [c["name"] for c in flush["children"]] == ["drain"]
+
+
 def test_preempt_demote_resume_bitwise_parity(served):
     """A gold arrival on a full 2-slot engine demotes a free-tenant
     victim (OP_CROSS_POOL_COPY into the spill slots), admits the NEXT
