@@ -379,10 +379,10 @@ class LanguageModel:
 
             h, (cs, ss) = jax.lax.scan(inner, h, (lp, cs, ss))
             h, (kp, vp), _ = tfm.decoder_layer_decode(
-                shared, h, pos, (kp, vp), ids, pos % page,
+                shared, h, pos, (kp[None], vp[None]), 0, ids, pos % page,
                 state["share_mask"], state["base"], seq_incl, cfg, mesh,
                 impl=impl, exclusive=exclusive)
-            return h, (cs, ss, kp, vp)
+            return h, (cs, ss, kp[0], vp[0])
 
         x, (cs, ss, kp, vp) = jax.lax.scan(
             segment, x, (seg_params, state["conv_state"], state["ssm_state"],

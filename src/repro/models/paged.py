@@ -187,16 +187,21 @@ def make_serving_pools(num_layers: int, nblk: int, page: int, kv_heads: int,
 # the per-layer decode step
 # ---------------------------------------------------------------------------
 
-def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
-                        blk_ids, offsets, share_mask, base, seq_lens,
-                        impl: str = "ref", exclusive: bool = False):
-    """Append this step's K/V then attend over the paged cache, under the
-    ``decode_attention`` named scope (its ops carry that scope in a device
-    trace).
+def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pools,
+                        v_pools, layer, blk_ids, offsets, share_mask, base,
+                        seq_lens, impl: str = "ref", exclusive: bool = False):
+    """Append this step's K/V to layer ``layer`` of the stacked pools, then
+    attend over that layer's paged cache, under the ``decode_attention``
+    named scope (its ops carry that scope in a device trace).
 
     q:        (B, H, D)      new-token queries, post-RoPE
     k_new/v_new: (B, KVH, D) new-token keys/values, post-RoPE
-    k_pool/v_pool: (nblk, page, KVH, D) — block axis sharded (pod,data,model)
+    k_pools/v_pools: (L, nblk, page, KVH, D) — the layer-stacked pools,
+                block axis (1) sharded (pod,data,model).  The token lands
+                at ``[layer, block, offset]`` by a scatter into the stack
+                itself and the attention reads layer ``layer``'s pages by
+                dynamic index, so only the new tokens' slots are written
+    layer:    int32 scalar   this layer's index into the stacks
     blk_ids:  (B,) int32     GLOBAL pool block id receiving this token
     offsets:  (B,) int32     slot within that block
     share_mask: block-readable-by-sequence bitmap, int8.  Its COLUMN COUNT
@@ -210,14 +215,14 @@ def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
     base:     (nblk,) int32  token offset of block within its sequence
     seq_lens: (B,) int32     sequence length INCLUDING the new token
 
-    Returns (out (B,H,D), k_pool', v_pool').
+    Returns (out (B,H,D), k_pools', v_pools').
     """
     with jax.named_scope("decode_attention"):
-        page = k_pool.shape[1]
+        page = k_pools.shape[2]
         if mesh is None or int(np.prod(mesh.devices.shape)) == 1:
-            return _attend_append_local(q, k_new, v_new, k_pool, v_pool,
-                                        blk_ids, offsets, share_mask, base,
-                                        seq_lens, page=page, impl=impl,
+            return _attend_append_local(q, k_new, v_new, k_pools, v_pools,
+                                        layer, blk_ids, offsets, share_mask,
+                                        base, seq_lens, page=page, impl=impl,
                                         exclusive=exclusive)
 
         B = q.shape[0]
@@ -230,8 +235,9 @@ def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
             # spans all axes)
             b_axes = ()
         bspec = _maybe(b_axes)
-        pspec = pool_spec(mesh)
-        mspec = P(pspec[0], None)
+        bkspec = pool_spec(mesh)                    # (nblk, ...) tables
+        pspec = pool_partition_spec(mesh, block_axis=1)
+        mspec = P(bkspec[0], None)
         comb = combine_axes(mesh, b_axes)
 
         fn = functools.partial(_attend_append_local, combine=comb,
@@ -239,30 +245,32 @@ def paged_attend_append(mesh: Optional[Mesh], q, k_new, v_new, k_pool, v_pool,
                                impl=impl, exclusive=exclusive)
         mapped = jax.shard_map(
             fn, mesh=mesh,
-            in_specs=(P(bspec), P(bspec), P(bspec), pspec, pspec,
-                      P(bspec), P(bspec), mspec, pspec, P(bspec)),
+            in_specs=(P(bspec), P(bspec), P(bspec), pspec, pspec, P(),
+                      P(bspec), P(bspec), mspec, bkspec, P(bspec)),
             out_specs=(P(bspec), pspec, pspec),
             check_vma=False,
         )
-        return mapped(q, k_new, v_new, k_pool, v_pool, blk_ids, offsets,
-                      share_mask, base, seq_lens)
+        return mapped(q, k_new, v_new, k_pools, v_pools, layer, blk_ids,
+                      offsets, share_mask, base, seq_lens)
 
 
-def _attend_append_local(q, k_new, v_new, k_slab, v_slab, blk_ids, offsets,
-                         share_mask, base, seq_lens, combine=(),
+def _attend_append_local(q, k_new, v_new, k_pools, v_pools, layer, blk_ids,
+                         offsets, share_mask, base, seq_lens, combine=(),
                          pool_axes=(), page=64, impl="ref",
                          exclusive=False):
-    slab = k_slab.shape[0]
+    slab = k_pools.shape[1]
     # blk_ids are global pool row numbers; this device's slab starts at the
     # shard-order offset over ALL axes sharding the pool.
     my0 = _slab_offset(pool_axes, slab) if pool_axes else jnp.int32(0)
     local = blk_ids - my0
     ok = (local >= 0) & (local < slab)
     safe = jnp.where(ok, local, slab)
-    k_slab = k_slab.at[safe, offsets].set(k_new.astype(k_slab.dtype),
-                                          mode="drop")
-    v_slab = v_slab.at[safe, offsets].set(v_new.astype(v_slab.dtype),
-                                          mode="drop")
+    k_pools = k_pools.at[layer, safe, offsets].set(
+        k_new.astype(k_pools.dtype), mode="drop")
+    v_pools = v_pools.at[layer, safe, offsets].set(
+        v_new.astype(v_pools.dtype), mode="drop")
+    k_slab = jax.lax.dynamic_index_in_dim(k_pools, layer, 0, keepdims=False)
+    v_slab = jax.lax.dynamic_index_in_dim(v_pools, layer, 0, keepdims=False)
     acc, l, m = paged_attention_slab(q, k_slab, v_slab, share_mask, base,
                                      seq_lens, page=page, impl=impl,
                                      exclusive=exclusive)
@@ -270,7 +278,7 @@ def _attend_append_local(q, k_new, v_new, k_slab, v_slab, blk_ids, offsets,
         out = lse_combine(acc, l, m, combine)
     else:
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype), k_slab, v_slab
+    return out.astype(q.dtype), k_pools, v_pools
 
 
 def _slab_offset(pool_axes: Tuple[str, ...], slab: int):

@@ -183,16 +183,19 @@ def decoder_stack_train(stacked, x, pos, cfg: ModelConfig, mesh,
 # decode layer (single token, paged KV)
 # ---------------------------------------------------------------------------
 
-def decoder_layer_decode(p, x, pos, pools, table_ids, offsets, share_mask, base,
-                         seq_lens_incl, cfg: ModelConfig, mesh,
-                         cross_kv=None, impl: str = "ref",
+def decoder_layer_decode(p, x, pos, pools, layer, table_ids, offsets,
+                         share_mask, base, seq_lens_incl, cfg: ModelConfig,
+                         mesh, cross_kv=None, impl: str = "ref",
                          exclusive: bool = False):
-    """x: (B, d); pools: (k_pool, v_pool) for THIS layer; pos: (B,).
+    """x: (B, d); pos: (B,); pools: (k_pools, v_pools), the layer-stacked
+    ``(L, nblk, page, KVH, D)`` pools; layer: int32 scalar, THIS layer's
+    index into them.
 
-    Returns (x', (k_pool', v_pool'), aux).
+    Returns (x', (k_pools', v_pools'), aux): only layer ``layer``'s pages
+    of the stacks change (this token's K/V).
     """
     B, d = x.shape
-    k_pool, v_pool = pools
+    k_pools, v_pools = pools
     h = rms_norm(x, p["ln1"].astype(jnp.float32), cfg.norm_eps)
     q, k, v = _qkv(p["attn"], h[:, None, :], cfg)   # (B,1,*)
     q = apply_rope(_heads(q, cfg.num_heads, cfg.head_dim),
@@ -200,9 +203,9 @@ def decoder_layer_decode(p, x, pos, pools, table_ids, offsets, share_mask, base,
     k = apply_rope(_heads(k, cfg.num_kv_heads, cfg.head_dim),
                    pos[:, None], cfg.rope_theta)[:, 0]
     v = _heads(v, cfg.num_kv_heads, cfg.head_dim)[:, 0]
-    o, k_pool, v_pool = paged_attend_append(
-        mesh, q, k, v, k_pool, v_pool, table_ids, offsets, share_mask, base,
-        seq_lens_incl, impl=impl, exclusive=exclusive)
+    o, k_pools, v_pools = paged_attend_append(
+        mesh, q, k, v, k_pools, v_pools, layer, table_ids, offsets,
+        share_mask, base, seq_lens_incl, impl=impl, exclusive=exclusive)
     x = x + o.reshape(B, cfg.q_dim) @ p["attn"]["wo"].astype(x.dtype)
 
     if cross_kv is not None:
@@ -224,31 +227,37 @@ def decoder_layer_decode(p, x, pos, pools, table_ids, offsets, share_mask, base,
         y = swiglu_mlp(h2[:, None, :], p["mlp"]["w_gate"], p["mlp"]["w_up"],
                        p["mlp"]["w_down"], mesh)[:, 0]
         aux = jnp.float32(0)
-    return x + y, (k_pool, v_pool), aux
+    return x + y, (k_pools, v_pools), aux
 
 
 def decoder_stack_decode(stacked, x, pos, k_pools, v_pools, table_ids,
                          offsets, share_mask, base, seq_lens_incl,
                          cfg: ModelConfig, mesh, cross_kvs=None,
                          impl: str = "ref", exclusive: bool = False):
-    """Scan decode over layers; pools are scan xs/ys (updated in place at the
-    XLA level via donation).  k_pools/v_pools: (L, nblk, page, KVH, D)."""
+    """Scan decode over layers.  k_pools/v_pools: (L, nblk, page, KVH, D).
+
+    The pools ride in the scan CARRY: each layer scatters its token into
+    the carried stacks at ``(layer, block, offset)`` and attends over its
+    own pages read out by layer index, so with the pools donated the
+    carry aliases them and the step writes only the new tokens' slots.
+    Donation does not make the ``xs``/``ys`` form in place: there the
+    compiler writes each layer's slab back into a second pool-sized
+    ``ys`` buffer and copies that whole buffer into the outputs."""
 
     def body(carry, inp):
-        h = carry
+        h, kp, vp = carry
         if cross_kvs is not None:
-            lp, kp, vp, xkv = inp
+            lp, layer, xkv = inp
         else:
-            lp, kp, vp = inp
-            xkv = None
+            (lp, layer), xkv = inp, None
         h, (kp, vp), _ = decoder_layer_decode(
-            lp, h, pos, (kp, vp), table_ids, offsets, share_mask, base,
-            seq_lens_incl, cfg, mesh, cross_kv=xkv, impl=impl,
+            lp, h, pos, (kp, vp), layer, table_ids, offsets, share_mask,
+            base, seq_lens_incl, cfg, mesh, cross_kv=xkv, impl=impl,
             exclusive=exclusive)
-        return h, (kp, vp)
+        return (h, kp, vp), None
 
-    xs = (stacked, k_pools, v_pools)
+    xs = (stacked, jnp.arange(k_pools.shape[0], dtype=jnp.int32))
     if cross_kvs is not None:
         xs = xs + (cross_kvs,)
-    x, (k_pools, v_pools) = jax.lax.scan(body, x, xs)
+    (x, k_pools, v_pools), _ = jax.lax.scan(body, (x, k_pools, v_pools), xs)
     return x, k_pools, v_pools

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the served drain for a described TPU v5e.
+"""Ahead-of-time compiles of the served drain and decode step for a
+described TPU v5e.
 
 Interpret mode runs the kernel bodies on the CPU but never meets the TPU
 compiler, which refuses what interpret mode accepts (unaligned slices,
@@ -13,6 +14,8 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +27,10 @@ from repro.core.cmdqueue import partition_commands
 from repro.core.opcodes import (OP_CROSS_POOL_COPY, OP_FPM_COPY, OP_NOP,
                                 OP_PSM_COPY, OP_ZERO_INIT)
 from repro.core.poolspec import PoolGroup, PoolSpec
+from repro.configs import get_config
 from repro.kernels import fused_dispatch as kfd
+from repro.launch.serve import ServingEngine
+from repro.models import build_model, split_params
 
 LAYERS, PAGE, KV_HEADS, HEAD_DIM = 28, 64, 8, 128   # llama3.2-3b
 NBLK, STAGE = 16 * 16, 16
@@ -135,3 +141,34 @@ def test_shard_plan_covers_the_round():
     assert plan.local_tables.shape[0] == 4
     assert plan.n_transfer == 1 and plan.n_local == 4
     assert int((plan.local_tables[..., 0] != OP_NOP).sum()) == 4
+
+
+def test_decode_step_compiles_in_place(topo):
+    """One chip: yi-6b's served decode step at published widths (4 of its
+    32 layers, bf16 weights and pools, 16 sequences x 16 pages of 64)
+    updates the donated pools in place: the temporaries stay under one
+    layer's K+V slab.  Passing the pools through the layer scan as
+    ``xs``/``ys`` held a whole second K+V pool (1.07 GB at 32 layers)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = dataclasses.replace(get_config("yi-6b"), num_layers=4)
+    with jax.default_device(jax.devices("cpu")[0]):
+        eng = ServingEngine(cfg, None, max_seqs=16, max_blocks_per_seq=16,
+                            max_admit_pages=16, adaptive_ring=False)
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one)
+
+    params = jax.eval_shape(
+        lambda k: split_params(build_model(cfg).init_params(k))[0],
+        jax.random.key(0))
+    params = jax.tree_util.tree_map(lambda x: sds(x, DTYPE), params)
+    k, v = eng.engine.pools["k"], eng.engine.pools["v"]
+    assert k.shape == (4, 256, PAGE, 4, HEAD_DIM) and k.dtype == DTYPE
+    table, mask, base = eng.cache.device_tables()
+    i32 = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one)
+    compiled = eng._decode_jit.lower(
+        params, sds(k), sds(v), sds(table), sds(mask), sds(base), i32, i32,
+        None).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= k.nbytes + v.nbytes
+    assert mem.temp_size_in_bytes < (k.nbytes + v.nbytes) // 4
