@@ -1,12 +1,24 @@
-"""Plain reference forward pass of the benchmark's decoder models, and the
-lower-precision control that proves the comparison can fail.
+"""The benchmark's decoder architecture: a grouped-query decoder with a
+SwiGLU feed-forward (``family`` dense), or the same decoder with a
+capacity-buffer mixture of experts (``family`` moe).  Everything the
+benchmark knows of its mathematics, from the configuration file's numbers
+alone:
 
-Straight ``jax.numpy`` in float32 at ``Precision.HIGHEST``, written from
-the configuration file's numbers: RMSNorm with a ``1 + w`` scale, rotary
-embeddings on the two halves of each head, grouped-query causal attention,
-a SwiGLU feed-forward or, for ``family == "moe"``, softmax top-k routing
-over every expert plus the shared experts.  It imports nothing of the
-program; its weights come from ``bench/weights.py`` and the same seed.
+- :func:`program_checks`: the program's config fields the file fixes;
+- :func:`weight_layout`: the leaves ``bench/weights.py`` draws from the
+  seed, the embedding and head outside one stack of identical layers;
+- the cost model ``bench/cost.py`` reads: :func:`matmul_weights_per_token`,
+  :func:`attn_flops_per_pair`, :func:`kv_block_bytes`,
+  :func:`primary_pools`;
+- :func:`logit_gaps`: the plain reference forward pass and the
+  lower-precision control that proves the comparison can fail.
+
+The reference is straight ``jax.numpy`` in float32 at
+``Precision.HIGHEST``: RMSNorm with a ``1 + w`` scale, rotary embeddings
+on the two halves of each head, grouped-query causal attention, a SwiGLU
+feed-forward or, for ``family == "moe"``, softmax top-k routing over every
+expert plus the shared experts.  It imports nothing of the program; its
+weights come from ``bench/weights.py`` and the same seed.
 
 It runs layer by layer over a batch of whole sequences (prompt plus the
 tokens the engine served), so only one layer's float32 weights are on the
@@ -30,6 +42,132 @@ import numpy as np
 
 from bench import weights as W
 
+BF16 = 2
+#: the program pads the vocabulary of its embedding and head to this
+VOCAB_MULTIPLE = 256
+
+
+# -- what the program must run ---------------------------------------------
+def program_checks(m: dict) -> dict:
+    """The program's ``ModelConfig`` fields and the values the file states
+    for them."""
+    want = {
+        "d_model": m["hidden_size"],
+        "num_layers": m["num_hidden_layers"],
+        "num_heads": m["num_attention_heads"],
+        "num_kv_heads": m["num_key_value_heads"],
+        "head_dim": m["head_dim"],
+        "vocab_size": m["vocab_size"],
+        "rope_theta": m["rope_theta"],
+        "norm_eps": m["rms_norm_eps"],
+        "tie_embeddings": m["tie_word_embeddings"],
+        "dtype": m["torch_dtype"],
+        "family": m["family"],
+    }
+    if m["family"] == "moe":
+        want.update(num_experts=m["n_routed_experts"],
+                    top_k=m["num_experts_per_tok"],
+                    num_shared_experts=m["n_shared_experts"],
+                    d_ff=m["moe_intermediate_size"])
+    else:
+        want["d_ff"] = m["intermediate_size"]
+    return want
+
+
+# -- the weights -----------------------------------------------------------
+def padded_vocab(m: dict) -> int:
+    v = m["vocab_size"]
+    return -(-v // VOCAB_MULTIPLE) * VOCAB_MULTIPLE
+
+
+def _top_specs(m: dict) -> List[W.Spec]:
+    """The leaves outside the layer stack."""
+    d, v = m["hidden_size"], padded_vocab(m)
+    specs = [("embed", (v, d), 0.02), ("final_norm", (d,), "norm")]
+    if not m["tie_word_embeddings"]:
+        specs.append(("lm_head", (d, v), 0.02))
+    return specs
+
+
+def _layer_specs(m: dict) -> List[W.Spec]:
+    """One decoder layer's leaves."""
+    d, h, kvh, hd = (m["hidden_size"], m["num_attention_heads"],
+                     m["num_key_value_heads"], m["head_dim"])
+    specs = [
+        ("ln1", (d,), "norm"),
+        ("attn/wq", (d, h * hd), d ** -0.5),
+        ("attn/wk", (d, kvh * hd), d ** -0.5),
+        ("attn/wv", (d, kvh * hd), d ** -0.5),
+        ("attn/wo", (h * hd, d), (h * hd) ** -0.5),
+        ("ln2", (d,), "norm"),
+    ]
+    if m["family"] == "moe":
+        e, f = m["n_routed_experts"], m["moe_intermediate_size"]
+        fs = m["n_shared_experts"] * f
+        specs += [
+            ("moe/router", (d, e), 0.02),
+            ("moe/w_gate", (e, d, f), d ** -0.5),
+            ("moe/w_up", (e, d, f), d ** -0.5),
+            ("moe/w_down", (e, f, d), f ** -0.5),
+            ("moe/shared/w_gate", (d, fs), d ** -0.5),
+            ("moe/shared/w_up", (d, fs), d ** -0.5),
+            ("moe/shared/w_down", (fs, d), fs ** -0.5),
+        ]
+    else:
+        f = m["intermediate_size"]
+        specs += [
+            ("mlp/w_gate", (d, f), d ** -0.5),
+            ("mlp/w_up", (d, f), d ** -0.5),
+            ("mlp/w_down", (f, d), f ** -0.5),
+        ]
+    return specs
+
+
+def weight_layout(m: dict) -> W.Layout:
+    """The program's parameter tree: embedding, final norm and head, and
+    every layer's leaves stacked under ``layers``."""
+    return _top_specs(m), [("layers", m["num_hidden_layers"],
+                            _layer_specs(m))]
+
+
+# -- the cost model --------------------------------------------------------
+def matmul_weights_per_token(m: dict) -> int:
+    """Weights one token multiplies by, over every layer and the head:
+    for a mixture of experts the router, its ``k`` routed experts and the
+    shared experts, not the buffer slots a capacity-buffer dispatch also
+    computes."""
+    d, h, kvh, hd = (m["hidden_size"], m["num_attention_heads"],
+                     m["num_key_value_heads"], m["head_dim"])
+    attn = d * h * hd + 2 * d * kvh * hd + h * hd * d
+    if m["family"] == "moe":
+        f = m["moe_intermediate_size"]
+        ffn = d * m["n_routed_experts"] \
+            + 3 * d * f * (m["num_experts_per_tok"] + m["n_shared_experts"])
+    else:
+        ffn = 3 * d * m["intermediate_size"]
+    v = m["vocab_size"]
+    return m["num_hidden_layers"] * (attn + ffn) + d * v
+
+
+def attn_flops_per_pair(m: dict) -> int:
+    """FLOPs of attention per (query, key) pair over every layer: the
+    score and the weighted value, 2 FLOPs per multiply-add each."""
+    return 4 * m["num_hidden_layers"] * m["num_attention_heads"] * m["head_dim"]
+
+
+def kv_block_bytes(m: dict, page: int) -> int:
+    """Bytes of one KV-pool block (every layer's chunk of ``page``
+    tokens) in ONE pool, K or V, in bf16."""
+    return (m["num_hidden_layers"] * page * m["num_key_value_heads"]
+            * m["head_dim"] * BF16)
+
+
+def primary_pools(m: dict) -> int:
+    """The KV pools a plain drain row acts on: K and V."""
+    return 2
+
+
+# -- the reference and its control -----------------------------------------
 HI = jax.lax.Precision.HIGHEST
 #: tokens per chunk of the LM head (bounds the float32 logits on device)
 HEAD_CHUNK = 256

@@ -4,8 +4,10 @@ the chip, and print what the compiler says they need.
     JAX_PLATFORMS=cpu python3 bench/rehearse_compile.py [config ...]
 
 For every configuration file (default: all under ``bench/configs``) it
-compiles, at the served sizes: the weight init of ``bench/weights.py``,
-the engine's decode step over the full pools, and its prefill of the
+compiles, at the served sizes: the weight init of ``bench/weights.py``
+over the configuration's weight layout (its module in
+``bench/references/``, resolved as ``bench/run.py`` resolves it), the
+engine's decode step over the full pools, and its prefill of the
 longest prompt the benchmark's mixes send.  It prints each program's
 ``memory_analysis()`` and the bytes the engine keeps resident (weights and
 pools), so a configuration that cannot fit one chip is found before a chip

@@ -5,8 +5,8 @@
 One run:
 
 1. builds the cell's ``ServingEngine`` (weights made on the device from
-   ``--seed`` by ``bench/weights.py``) and wraps it in a
-   ``RequestScheduler`` with the mix's tenants;
+   ``--seed`` by ``bench/weights.py``, in the configuration's weight
+   layout) and wraps it in a ``RequestScheduler`` with the mix's tenants;
 2. warms every shape the window will use: one request of each prompt
    length, every drain bucket, then ``warmup_s`` of the cell's own traffic;
 3. drives the window with the traffic for ``--seconds`` through
@@ -15,7 +15,8 @@ One run:
    per-layer metrics (``bench/metrics``); without, reports the end-to-end
    metrics;
 5. frees the engine and checks a seeded sample of the finished requests
-   against the plain reference (``bench/reference.py``): ``correct`` when
+   against the plain reference (``logit_gaps`` of the configuration's
+   module, ``bench/references/<reference>.py``): ``correct`` when
    every number compared (``max_logit_gap``, the widest gap by which a
    served token's logit lies below the reference's best, and any other
    the configuration's ``correct.limits`` names) is within its limit.
@@ -44,8 +45,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import spec, stats, weights  # noqa: E402
-from bench import cost  # noqa: E402
+from bench import cost, spec, stats, weights  # noqa: E402
 
 #: seconds at the end of the window the ``--trace 1`` run traces (device
 #: traces of whole windows are too large to reduce in a run's time)
@@ -91,29 +91,11 @@ class Failed(RuntimeError):
 def program_config(m: dict):
     """The program's ModelConfig for configuration file ``m``: the
     registry entry ``arch`` with ``overrides``, checked against every
-    number the file states, so the file is what runs."""
+    number the file states (``program_checks`` of its module), so the
+    file is what runs."""
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(m["arch"]), **m["overrides"])
-    want = {
-        "d_model": m["hidden_size"],
-        "num_layers": m["num_hidden_layers"],
-        "num_heads": m["num_attention_heads"],
-        "num_kv_heads": m["num_key_value_heads"],
-        "head_dim": m["head_dim"],
-        "vocab_size": m["vocab_size"],
-        "rope_theta": m["rope_theta"],
-        "norm_eps": m["rms_norm_eps"],
-        "tie_embeddings": m["tie_word_embeddings"],
-        "dtype": m["torch_dtype"],
-        "family": m["family"],
-    }
-    if m["family"] == "moe":
-        want.update(num_experts=m["n_routed_experts"],
-                    top_k=m["num_experts_per_tok"],
-                    num_shared_experts=m["n_shared_experts"],
-                    d_ff=m["moe_intermediate_size"])
-    else:
-        want["d_ff"] = m["intermediate_size"]
+    want = spec.reference(m).program_checks(m)
     bad = {k: (getattr(cfg, k), v) for k, v in want.items()
            if getattr(cfg, k) != v}
     if bad:
@@ -137,7 +119,8 @@ def build_engine(m: dict, seed: int):
             a.shape != b.shape for a, b in zip(
                 jax.tree_util.tree_leaves(params),
                 jax.tree_util.tree_leaves(want))):
-        raise ValueError("bench/weights.py tree differs from the program's "
+        raise ValueError(f"the weight layout of bench/references/"
+                         f"{m['reference']}.py differs from the program's "
                          "parameter layout")
     e = m["engine"]
     return ServingEngine(cfg, params, max_seqs=e["max_seqs"],
@@ -338,22 +321,23 @@ def compare(m: dict, seed: int, drv: TrafficLoop, sample: List[int],
     gap by which a served token's logit lies below the reference's best
     (and, with ``control``, the widest gap of the int8 forward's picks).
 
-    For an MoE configuration a position is compared only where the
-    reference routes it clearly at every layer: its k-th router logit
-    lies above the next by at least ``correct.route_margin`` times the
-    root mean square of its router logits.  A nearer tie
-    falls either way under bf16 rounding and moves the token by a whole
-    expert's share; ``skipped_share`` says how many positions that left
-    out."""
-    from bench import reference
+    Where the reference routes (its ``margin`` is finite, as for an MoE
+    configuration), a position is compared only where it routes clearly
+    at every layer: its k-th router logit lies above the next by at least
+    ``correct.route_margin`` times the root mean square of its router
+    logits.  A nearer tie falls either way under bf16 rounding and moves
+    the token by a whole expert's share; ``skipped_share`` says how many
+    positions that left out, and ``by_margin`` reads the gaps at a range
+    of margins."""
     if not sample:
         return {"max_logit_gap": float("inf"), "mean_logit_gap": float("inf"),
                 "control_max_logit_gap": float("inf"), "skipped_share": 1.0,
                 "tokens": 0, "requests": 0, "preempted": 0,
                 "complete": False}
     recs = [drv.req[r] for r in sample]
-    gaps = reference.logit_gaps(m, seed, [r["prompt"] for r in recs],
-                                [r["served"] for r in recs], control=control)
+    gaps = spec.reference(m).logit_gaps(
+        m, seed, [r["prompt"] for r in recs], [r["served"] for r in recs],
+        control=control)
     every = np.concatenate([g["gap"] for g in gaps])
     margin = np.concatenate([g["margin"] for g in gaps])
     keep = margin >= float(m["correct"].get("route_margin", 0.0))
@@ -369,7 +353,7 @@ def compare(m: dict, seed: int, drv: TrafficLoop, sample: List[int],
         out["control_max_logit_gap"] = _max(ctrl[keep])
         out["control_quantiles"] = [float(np.quantile(ctrl, q))
                                     for q in (0.5, 0.9, 0.99, 0.999)]
-    if m["family"] == "moe":
+    if np.isfinite(margin).any():
         # (margin, program's widest gap, control's, share left out)
         out["by_margin"] = [
             [mg, _max(every[margin >= mg]),
@@ -562,6 +546,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
         "output_tokens_per_s": tokens / window_s,
         "ttft_p50_ms": (stats.percentile(ttft, 50) or 0.0) * 1e3,
         "itl_p95_ms": (stats.percentile(itl, 95) or 0.0) * 1e3,
+        "itl_mean_ms": (stats.mean(itl) or 0.0) * 1e3,
         "setup_s": st.setup_s,
     }
     log(f"[bench] window {window_s:.3f} s: {len(win_rounds)} rounds, "
@@ -573,7 +558,8 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
         f"({sum(1 for r in due_in if r['first_token'] is None)} censored)")
     log(f"[bench] itl p50 {stats.percentile(itl, 50)} s, p90 "
         f"{stats.percentile(itl, 90)} s, p95 {stats.percentile(itl, 95)} s, "
-        f"p99 {stats.percentile(itl, 99)} s over {len(itl)} gaps")
+        f"p99 {stats.percentile(itl, 99)} s, mean {e2e['itl_mean_ms']} ms "
+        f"over {len(itl)} gaps")
     log(f"[bench] tpot p50 {stats.percentile(tpot, 50)} s, p90 "
         f"{stats.percentile(tpot, 90)} s over {len(tpot)} requests")
     log(f"[bench] generator lateness p50 {stats.percentile(late, 50)} s, "
@@ -584,6 +570,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
     log(f"[bench] peak_bytes_in_use {dev['memory_peak_bytes']}")
     host = {
         "queue_wait_s": [r["admitted"] - r["due"] for r in admitted],
+        "itl_s": itl,
         "flops": sum(x["flops"] for x in win_rounds),
         "rounds_s": sum(x["t_b"] - x["t_a"] for x in win_rounds),
         "traced_prefill_tokens": 0,

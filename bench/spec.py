@@ -3,8 +3,12 @@
 A cell names a configuration and a traffic mix; each resolves by name to a
 file under ``bench/``, the cell's own load (an open loop's rate) to
 ``bench/cells/<cell>.json``, and each per-layer metric to a reader
-``bench/metrics/<metric>.py``.  Adding a configuration, a mix or a metric
-therefore means adding files and entries, never editing one.
+``bench/metrics/<metric>.py``.  A configuration file names its
+architecture's module, ``"reference": "<name>"`` for
+``bench/references/<name>.py``: the program checks, the weight layout,
+the plain reference and control, and the cost model.  Adding a
+configuration, an architecture, a mix or a metric therefore means adding
+files and entries, never editing one.
 """
 from __future__ import annotations
 
@@ -22,6 +26,11 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES_E2E = ("host_clock", "device_trace")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+MODULE_RE = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
+#: what an architecture's module in ``references/`` defines
+REFERENCE_FUNCTIONS = ("program_checks", "weight_layout", "logit_gaps",
+                       "matmul_weights_per_token", "attn_flops_per_pair",
+                       "kv_block_bytes", "primary_pools")
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:
@@ -53,6 +62,36 @@ def config_entry(bench: dict, name: str) -> dict:
 def config_file(bench: dict, name: str, root: pathlib.Path = ROOT) -> dict:
     """The configuration as it is run (``configs[].file``)."""
     return load_json(root / config_entry(bench, name)["file"])
+
+
+def reference(m: dict):
+    """The module of configuration ``m``'s architecture,
+    ``references/<m["reference"]>.py``."""
+    name = m.get("reference")
+    if not isinstance(name, str) or not MODULE_RE.match(name):
+        raise KeyError(f"config {m.get('name')}: reference {name!r} names "
+                       "no module of bench/references")
+    return importlib.import_module(f"bench.references.{name}")
+
+
+def reference_problems(m: dict) -> List[str]:
+    """What keeps configuration ``m``'s architecture module from
+    resolving; empty when it does."""
+    what = f"config {m.get('name')}"
+    name = m.get("reference")
+    if name is None:
+        return [f"{what}: no reference"]
+    try:
+        mod = reference(m)
+    except KeyError as e:
+        return [e.args[0]]
+    except ModuleNotFoundError as e:
+        if e.name != f"bench.references.{name}":
+            raise
+        return [f"{what}: no module references/{name}.py"]
+    lack = [f for f in REFERENCE_FUNCTIONS
+            if not callable(getattr(mod, f, None))]
+    return [f"{what}: references/{name}.py lacks {lack}"] if lack else []
 
 
 def traffic_file(name: str) -> dict:
@@ -132,10 +171,12 @@ def problems(bench: dict, root: pathlib.Path = ROOT) -> List[str]:
         path = root / c["file"]
         if not path.is_file():
             out.append(f"config {c['name']}: no file {c['file']}")
-        elif path.parent != BENCH_DIR / "configs" \
+            continue
+        if path.parent != root / BENCH_DIR.name / "configs" \
                 or path.stem != c["name"]:
             out.append(f"config {c['name']}: file is not "
                        f"configs/{c['name']}.json")
+        out += reference_problems(load_json(path))
     cells = []
     for w in bench["workloads"]:
         name_ok(w["name"], "workload")

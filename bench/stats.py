@@ -22,6 +22,12 @@ def percentile(values: Iterable[float], q: float) -> Optional[float]:
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
+def mean(values: Iterable[float]) -> Optional[float]:
+    """The mean of every sample; None when empty."""
+    xs = [float(v) for v in values]
+    return sum(xs) / len(xs) if xs else None
+
+
 def ttft_samples(requests: Sequence[Dict], t_end: float) -> List[float]:
     """Time to first token, in seconds, of every request due in the
     window, timed from its DUE time.  A request with no first token by

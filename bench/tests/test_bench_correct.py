@@ -19,7 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from bench import reference, run, spec, weights  # noqa: E402
+from bench import run, spec, weights  # noqa: E402
 
 FIX = spec.BENCH_DIR / "tests" / "fixtures"
 SECONDS = 2.0
@@ -141,5 +141,5 @@ def test_reference_agrees_with_the_program_in_float32(name):
             lg = np.asarray(prefill(params, jnp.asarray(p[None])))[0]
         prompts.append(p)
         served.append([int(np.argmax(lg))])
-    gaps = reference.logit_gaps(m, 11, prompts, served)
+    gaps = spec.reference(m).logit_gaps(m, 11, prompts, served)
     assert max(float(g["gap"].max()) for g in gaps) < 0.02

@@ -49,6 +49,13 @@ def test_inter_token_gaps_keep_only_gaps_ending_in_the_window():
         [0.5, 2.5])
 
 
+def test_mean_is_every_sample_over_their_count():
+    times = {1: [0.5, 1.5, 2.0, 4.5], 2: [3.0], 3: [2.0, 2.5]}
+    gaps = stats.inter_token_gaps(times, 1.6, 5.0)    # 0.5, 2.5, 0.5
+    assert stats.mean(gaps) == pytest.approx(3.5 / 3)
+    assert stats.mean([]) is None
+
+
 def test_time_per_output_token_is_each_requests_mean_gap_in_window():
     times = {1: [0.5, 1.5, 2.0, 4.5], 2: [3.0], 3: [2.0, 2.5]}
     # request 1: tokens 2.0 and 4.5 inside (1.6, 5.0]; 2: one token

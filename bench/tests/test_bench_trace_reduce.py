@@ -128,3 +128,23 @@ def test_metric_readers_on_the_recorded_trace():
     assert read("queue_wait_p90_ms") == pytest.approx(280.0)
     run["host"]["traced_drain_rows"] = {}
     assert read("drain_roofline") is None      # nothing to read: silent
+
+
+def test_gap_readers_read_every_gap_of_the_window():
+    """``itl_p95_ms.open`` and ``stall_gap_share`` read the window's
+    inter-token gaps from the host clock; with none they read nothing."""
+    gaps = [0.027] * 95 + [0.055] * 5
+    run = {"host": {"itl_s": gaps}}
+
+    def read(name):
+        return spec.metric_reader(name)(run)
+
+    assert read("itl_p95_ms.open") == pytest.approx(
+        (0.027 + (0.055 - 0.027) * 0.05) * 1e3)
+    assert read("stall_gap_share") == pytest.approx(5.0)
+    run["host"]["itl_s"] = gaps[:-1]
+    assert read("stall_gap_share") == pytest.approx(4 / 99 * 100)
+    assert read("itl_p95_ms.open") == pytest.approx(27.0)
+    run["host"]["itl_s"] = []
+    assert read("itl_p95_ms.open") is None
+    assert read("stall_gap_share") is None
